@@ -49,8 +49,7 @@ def save(path: str, tree, step: int, async_: bool = True):
     manifest = {
         "step": step,
         "treedef": str(treedef),
-        "leaves": [{"shape": list(np.asarray(jax.device_get(x)).shape),
-                    "dtype": str(np.asarray(jax.device_get(x)).dtype)}
+        "leaves": [{"shape": list(np.shape(x)), "dtype": str(x.dtype)}
                    for x in leaves],
     }
 

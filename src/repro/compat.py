@@ -1,69 +1,19 @@
-"""jax version-compatibility shims — single import point for drifting APIs.
+"""Mesh and shard_map import point.
 
-The codebase targets current jax (>= 0.6) but must run on older installs
-(0.4.x).  Every module imports the moving pieces from here instead of jax:
-
-  * ``shard_map``   — ``jax.shard_map`` (new) vs ``jax.experimental.shard_map``
-                      (old); the replication-check kwarg is ``check_vma`` on
-                      new jax and ``check_rep`` on old — we accept ``check_vma``
-                      and translate.
-  * ``axis_size``   — ``jax.lax.axis_size`` (new); on old jax ``psum(1, axis)``
-                      constant-folds to the same static int inside shard_map.
-  * ``make_mesh``   — always requests Auto axis types where the install
-                      supports ``jax.sharding.AxisType``; silently drops the
-                      argument where it doesn't (old jax meshes are Auto-only).
-  * ``ragged_all_to_all`` — added in jax 0.5.1; unavailable installs raise at
-                      call time (the ragged engine is TPU-only anyway).
+``make_mesh`` requests Auto axis types: ``jax.make_mesh`` defaults to
+Explicit axes, and every sharding rule in this repo is written for
+compiler-propagated (Auto) shardings.
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-try:  # new-style top-level export (jax >= 0.6)
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_raw_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """``jax.shard_map`` with the new-style ``check_vma`` kwarg everywhere."""
-    if "check_vma" in _SHARD_MAP_PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _SHARD_MAP_PARAMS:
-        kw["check_rep"] = check_vma
-    return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, from inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)  # constant-folds to the axis size
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes, axis_names, **kw):
-    """``jax.make_mesh`` with Auto axis types when the install has them."""
-    if not hasattr(jax, "make_mesh"):  # pragma: no cover - pre-0.4.35 jax
-        from jax.experimental import mesh_utils
-        return jax.sharding.Mesh(
-            mesh_utils.create_device_mesh(axis_shapes), axis_names)
-    if "axis_types" not in kw and hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    kw.setdefault("axis_types",
+                  (jax.sharding.AxisType.Auto,) * len(axis_names))
     return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-HAS_RAGGED_ALL_TO_ALL = hasattr(jax.lax, "ragged_all_to_all")
-
-
-def ragged_all_to_all(*args, **kw):
-    if not HAS_RAGGED_ALL_TO_ALL:  # pragma: no cover - depends on installed jax
-        raise NotImplementedError(
-            "jax.lax.ragged_all_to_all needs jax >= 0.5.1 (the 'ragged' "
-            "engine is TPU-only; CPU tests cover descriptor construction)")
-    return jax.lax.ragged_all_to_all(*args, **kw)

@@ -22,9 +22,10 @@ Probes (min-of-repeats, post-compile, ``block_until_ready``):
                 pattern as the Pallas staging kernels (read + write counted)
     wire_bw     a timed ``device_put`` of the buffer to another device when
                 one exists (host-platform CPU "devices" give a copy-bandwidth
-                proxy; single-device falls back to stage_bw / 4 so the
-                wire-slower-than-staging invariant the simulator assumes
-                still holds)
+                proxy).  With one device there is no wire to time: the table
+                carries stage_bw / 4, an assumption that keeps the
+                wire-slower-than-staging ordering the simulator needs, and
+                marks it ``wire_measured=False``
     overhead_s  a jitted scalar op — pure dispatch latency
 
 Measured rates are clamped to sane positive-finite bounds: a calibration
@@ -58,6 +59,7 @@ class CalibrationTable:
     overhead_s: float        # seconds per dispatch
     platform: str = "unknown"
     payload_bytes: int = 0
+    wire_measured: bool = True   # False: wire_bw is assumed, not timed
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -95,14 +97,15 @@ def calibrate(payload_bytes: int = 1 << 22,
     stage_bw = 2.0 * actual_bytes / t_stage      # read + write
 
     devices = jax.devices()
-    if len(devices) > 1:
+    wire_measured = len(devices) > 1
+    if wire_measured:
         src = jax.device_put(x, devices[0])
         t_wire = _timeit(
             lambda: jax.device_put(src, devices[1]).block_until_ready(),
             repeats)
         wire_bw = actual_bytes / t_wire
     else:
-        wire_bw = stage_bw / 4.0                 # keep wire < stage ordering
+        wire_bw = stage_bw / 4.0                 # assumed: wire < stage
 
     tiny = jnp.zeros((8,), jnp.float32)
     reduce = jax.jit(jnp.sum)
@@ -114,6 +117,7 @@ def calibrate(payload_bytes: int = 1 << 22,
         overhead_s=_clamp(overhead, _MIN_OVH, _MAX_OVH),
         platform=jax.default_backend(),
         payload_bytes=actual_bytes,
+        wire_measured=wire_measured,
     )
 
 

@@ -98,7 +98,6 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size, ragged_all_to_all
 from repro.core import pipesim
 from repro.core import planner as planner_lib
 from repro.core.descriptors import drop_neg, gather_rows
@@ -148,7 +147,7 @@ def _lane_index(cfg: DcommConfig, placement: ExpertPlacement) -> jax.Array:
     m = jax.lax.axis_index(cfg.model_axis)
     if cfg.pod_axis is not None:
         p = jax.lax.axis_index(cfg.pod_axis)
-        return p * (placement.ep // axis_size(cfg.pod_axis)) + m
+        return p * (placement.ep // jax.lax.axis_size(cfg.pod_axis)) + m
     return m
 
 
@@ -185,7 +184,7 @@ def _flat_exchange(buf: jax.Array, cfg: DcommConfig, ep: int,
     """
     if cfg.pod_axis is None:
         return jax.lax.all_to_all(buf, cfg.model_axis, 0, 0, tiled=True)
-    npod = axis_size(cfg.pod_axis)
+    npod = jax.lax.axis_size(cfg.pod_axis)
     buf = buf.reshape((npod, ep // npod) + buf.shape[1:])
     if reverse:
         buf = jax.lax.all_to_all(buf, cfg.pod_axis, 0, 0, tiled=True)
@@ -560,7 +559,7 @@ def hier_dispatch(x: jax.Array, A: jax.Array, gates: jax.Array,
     me = plan1.meta_expert                                   # (EP*C1, K)
     mg = plan1.meta_gate
     if cfg.pod_axis is not None:
-        npod = axis_size(cfg.pod_axis)
+        npod = jax.lax.axis_size(cfg.pod_axis)
 
         def _ex(v):
             v = v.reshape((npod, placement.ep // npod, c1) + v.shape[2:])
@@ -617,7 +616,7 @@ def hier_combine(expert_out: jax.Array, res: DispatchResult,
         placement.ep * c1)
     # return over the slow tier (deduplicated bytes both directions)
     if cfg.pod_axis is not None:
-        npod = axis_size(cfg.pod_axis)
+        npod = jax.lax.axis_size(cfg.pod_axis)
         part = part.reshape(npod, placement.ep // npod, c1, d)
         part = jax.lax.all_to_all(part, cfg.pod_axis, 0, 0, tiled=True)
         part = jax.lax.all_to_all(part, cfg.model_axis, 1, 1, tiled=True)
@@ -798,7 +797,7 @@ def ragged_dispatch(x: jax.Array, A: jax.Array, gates: jax.Array,
                                  jnp.cumsum(recv_sizes)[:-1].astype(I32)])
     out_offsets = _a2a_vec(recv_offs, placement.ep, cfg.model_axis)
     out_buf = jnp.zeros((placement.ep * e_local * cap, d), x.dtype)
-    landed = ragged_all_to_all(
+    landed = jax.lax.ragged_all_to_all(
         send_buf, out_buf, offs, send_sizes, out_offsets, recv_sizes,
         axis_name=cfg.model_axis)
     return DispatchResult(landed.reshape(1, 1, placement.ep * e_local * cap, d),
@@ -824,7 +823,7 @@ def ragged_combine(expert_out: jax.Array, res: DispatchResult,
     rev_in_offs, rev_send_sizes, rev_out_offs, rev_recv_sizes = rev
     flat = expert_out.reshape(-1, d)
     back_buf = jnp.zeros((desc.compact_src.shape[0], d), flat.dtype)
-    back = ragged_all_to_all(
+    back = jax.lax.ragged_all_to_all(
         flat, back_buf, rev_in_offs, rev_send_sizes, rev_out_offs,
         rev_recv_sizes, axis_name=cfg.model_axis)
     w = desc.compact_gate[:, None].astype(back.dtype)

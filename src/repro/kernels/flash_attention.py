@@ -30,11 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -67,13 +63,13 @@ def _flash_kernel(qmn_ref, qmx_ref, kmn_ref, kmx_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (qb, kb)
-        qpos = qp_ref[0]                                 # (qb,) int32
-        kpos = kp_ref[0]                                 # (kb,)
+        qpos = qp_ref[0]                                 # (qb, 1) int32
+        kpos = kp_ref[0]                                 # (1, kb)
         mask = jnp.ones_like(s, jnp.bool_)
         if causal:
-            mask &= qpos[:, None] >= kpos[None, :]
+            mask &= qpos >= kpos
         if window is not None:
-            mask &= qpos[:, None] - kpos[None, :] < window
+            mask &= qpos - kpos < window
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]                              # (qb, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -89,7 +85,7 @@ def _flash_kernel(qmn_ref, qmx_ref, kmn_ref, kmx_ref,
     def _out():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_block",
@@ -110,17 +106,21 @@ def _flash_fwd_pallas(q, k, v, q_positions, k_positions, causal, window,
     qr = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, sq, hd)
     kr = k.transpose(0, 2, 1, 3)                         # (B, Hkv, Sk, hd)
     vr = v.transpose(0, 2, 1, 3)
-    qp = q_positions.astype(jnp.int32).reshape(nq, qb)
-    kp = k_positions.astype(jnp.int32).reshape(nk, kb)
-    qmn, qmx = qp.min(axis=1), qp.max(axis=1)
-    kmn, kmx = kp.min(axis=1), kp.max(axis=1)
+    # positions as a column per q-block and a row per kv-block, so each
+    # block's last two dims are full or (8, 128)-aligned on the TPU
+    qp = q_positions.astype(jnp.int32).reshape(nq, qb, 1)
+    kp = k_positions.astype(jnp.int32).reshape(nk, 1, kb)
+    qmn, qmx = qp.min(axis=(1, 2)), qp.max(axis=(1, 2))
+    kmn, kmx = kp.min(axis=(1, 2)), kp.max(axis=(1, 2))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                # qmin, qmax, kmin, kmax bounds
         grid=(b, hkv, g, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, qb), lambda bi, hi, gi, qi, ki, *s: (qi, 0)),
-            pl.BlockSpec((1, kb), lambda bi, hi, gi, qi, ki, *s: (ki, 0)),
+            pl.BlockSpec((1, qb, 1),
+                         lambda bi, hi, gi, qi, ki, *s: (qi, 0, 0)),
+            pl.BlockSpec((1, 1, kb),
+                         lambda bi, hi, gi, qi, ki, *s: (ki, 0, 0)),
             pl.BlockSpec((1, 1, 1, qb, hd),
                          lambda bi, hi, gi, qi, ki, *s: (bi, hi, gi, qi, 0)),
             pl.BlockSpec((1, 1, kb, hd),
@@ -131,8 +131,8 @@ def _flash_fwd_pallas(q, k, v, q_positions, k_positions, causal, window,
         out_specs=[
             pl.BlockSpec((1, 1, 1, qb, hd),
                          lambda bi, hi, gi, qi, ki, *s: (bi, hi, gi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, qb),
-                         lambda bi, hi, gi, qi, ki, *s: (bi, hi, gi, qi)),
+            pl.BlockSpec((1, 1, 1, qb, 1),
+                         lambda bi, hi, gi, qi, ki, *s: (bi, hi, gi, qi, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((qb, hd), jnp.float32),
                         pltpu.VMEM((qb, 1), jnp.float32),
@@ -143,7 +143,7 @@ def _flash_fwd_pallas(q, k, v, q_positions, k_positions, causal, window,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, hkv, g, sq, hd), q.dtype),
-                   jax.ShapeDtypeStruct((b, hkv, g, sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, hkv, g, sq, 1), jnp.float32)],
         interpret=interpret,
     )
     o, lse = fn(qmn, qmx, kmn, kmx, qp, kp, qr, kr, vr)
@@ -154,7 +154,7 @@ def _flash_fwd_pallas(q, k, v, q_positions, k_positions, causal, window,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def flash_attention(q, k, v, q_positions, k_positions, causal=True,
-                    window=None, q_block=512, kv_block=512, interpret=True):
+                    window=None, q_block=512, kv_block=512, interpret=False):
     """Pallas flash attention, position-safe (shifted island chunks / offset
     layouts mask and block-skip correctly).  Same signature/semantics as
     ``layers.attention.flash_attention`` plus ``interpret`` (CPU validation

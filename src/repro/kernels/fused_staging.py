@@ -31,10 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-only helpers; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _divisor_block(n: int, target: int) -> int:
@@ -83,7 +80,7 @@ def _swiglu_kernel(counts_ref, x_ref, w1_ref, w3_ref, w2_ref, out_ref,
 def fused_swiglu_pallas(x: jax.Array, w1: jax.Array, w3: jax.Array,
                         w2: jax.Array, counts: jax.Array, *,
                         block_c: int = 128, block_f: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """x: (S, E, C, d) landed rows; w1/w3: (E, d, f); w2: (E, f, d);
     counts: (S, E) group occupancy.  Returns (S, E, C, d) expert outputs with
     rows >= counts zeroed.  Differentiate via ``kernels.ops.fused_swiglu``

@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gmm_kernel(counts_ref, x_ref, w_ref, out_ref, acc_ref, *, block_c):
@@ -59,7 +56,7 @@ def _gmm_kernel(counts_ref, x_ref, w_ref, out_ref, acc_ref, *, block_c):
                                     "interpret"))
 def grouped_matmul(x: jax.Array, w: jax.Array, counts: jax.Array, *,
                    block_c: int = 128, block_f: int = 128,
-                   block_d: int = 128, interpret: bool = True) -> jax.Array:
+                   block_d: int = 128, interpret: bool = False) -> jax.Array:
     """x: (G, C, d) grouped rows; w: (G, d, f); counts: (G,) occupancy.
 
     Returns (G, C, f) = x @ w per group; rows at positions >= counts[g]

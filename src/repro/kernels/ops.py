@@ -1,8 +1,10 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-On TPU the Pallas (Mosaic) path runs natively; on CPU the kernels execute in
-``interpret=True`` (the kernel body evaluated op-by-op — used for correctness
-validation) or fall back to the jnp reference for speed.  The dense_fused
+On TPU the Pallas (Mosaic) path always runs natively: there is no switch to
+the jnp references there, and ``REPRO_USE_PALLAS=0`` is refused.  On CPU the
+references run by default, and ``REPRO_USE_PALLAS=1`` runs the kernels in
+``interpret=True`` (the kernel body evaluated op-by-op — correctness
+validation only).  The dense_fused
 dComm engines route their staging copies through :func:`segment_gather` /
 :func:`segment_scatter_add`, the expert FFN through :func:`fused_swiglu`,
 and the tx-island attention core through :func:`flash_attention` —
@@ -41,13 +43,18 @@ def backend() -> str:
 
 def use_pallas() -> bool:
     env = os.environ.get("REPRO_USE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "")
-    return backend() == "tpu"
+    off = env is not None and env in ("0", "false", "")
+    if backend() == "tpu":
+        if off:
+            raise RuntimeError(
+                f"REPRO_USE_PALLAS={env!r} on a TPU: the Pallas kernels are "
+                "the TPU path; the jnp references are CPU test oracles")
+        return True
+    return env is not None and not off
 
 
 def _interpret() -> bool:
-    return backend() != "tpu"
+    return backend() == "cpu"
 
 
 # ------------------------------------------------------- descriptor copies --

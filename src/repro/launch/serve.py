@@ -13,21 +13,26 @@ continuous-batching engine instead of one lock-step batch.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import zoo
 from repro.models.lm import make_context
-from repro.serving.engine import ContinuousServingEngine
+from repro.parallel.sharding import init_params_sharded
+from repro.serving.engine import ContinuousServingEngine, greedy
 
 
 def _run_continuous(bundle, params, args, max_len):
+    # every prompt the launcher submits is --prompt-len long: one bucket
     eng = ContinuousServingEngine(bundle, max_batch=args.requests,
-                                  max_len=max_len)
+                                  max_len=max_len,
+                                  buckets=(args.prompt_len,))
     compile_s = eng.warmup(params)
     rng = jax.random.PRNGKey(1)
     for i in range(args.requests):
@@ -50,6 +55,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-moe-30b-a3b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (widths unchanged)")
     ap.add_argument("--engine", default="fused_hier")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=64)
@@ -71,6 +78,10 @@ def main(argv=None):
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        print(f"depth cut: {cfg.name} {cfg.n_layers} -> {args.layers} layers")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    enable_compile_cache()
     mesh = make_host_mesh()
     ctx = make_context(cfg, mesh, multi_pod=False, engine=args.engine,
                        node_size=max(1, mesh.shape["model"] // 2),
@@ -80,10 +91,13 @@ def main(argv=None):
     key = jax.random.PRNGKey(0)
     max_len = args.prompt_len + args.gen
 
+    def init(k):
+        return jax.tree.map(lambda x: x.astype(jnp.bfloat16)
+                            if x.dtype == jnp.float32 else x, bundle.init(k))
+
     with mesh:
-        params = jax.tree.map(lambda x: x.astype(jnp.bfloat16)
-                              if x.dtype == jnp.float32 else x,
-                              bundle.init(key))
+        params = init_params_sharded(init, key, mesh,
+                                     fsdp_experts=ctx.fsdp_experts)
         if args.continuous:
             if cfg.family == "encdec":
                 ap.error("--continuous supports decoder-only families")
@@ -93,32 +107,30 @@ def main(argv=None):
         if cfg.family == "encdec":
             batch = {"frames": batch["frames"], "tokens": batch["tokens"][:, 0]}
 
-        prefill = jax.jit(lambda p, b: bundle.prefill(p, b, max_len))
-        decode = jax.jit(lambda p, st, t: bundle.decode_step(p, st, t, max_len))
+        # greedy sampling runs inside both executables
+        prefill = jax.jit(lambda p, b: greedy(bundle.prefill(p, b, max_len)))
+        decode = jax.jit(lambda p, st, t: greedy(
+            bundle.decode_step(p, st, t, max_len)))
 
         # warm up both executables (two decode steps cover the state-sharding
         # variants the jit caches) before the clock starts, so TTFT is
         # latency, not latency + jit
         t0 = time.perf_counter()
-        logits, state = prefill(params, batch)
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        tok, state = prefill(params, batch)
         for _ in range(2):
-            logits, state = decode(params, state, tok)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            tok, state = decode(params, state, tok)
         jax.block_until_ready(tok)
         compile_s = time.perf_counter() - t0
         print(f"compile+warmup {compile_s:.2f} s")
 
         t0 = time.perf_counter()
-        logits, state = prefill(params, batch)
-        jax.block_until_ready(logits)
+        tok, state = prefill(params, batch)
+        jax.block_until_ready(tok)
         ttft = time.perf_counter() - t0
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
         seqs = [tok]
         t0 = time.perf_counter()
         for _ in range(args.gen - 1):
-            logits, state = decode(params, state, tok)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            tok, state = decode(params, state, tok)
             seqs.append(tok)
         jax.block_until_ready(tok)
         t_dec = time.perf_counter() - t0

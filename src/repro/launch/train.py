@@ -11,16 +11,18 @@ import argparse
 import dataclasses
 import os
 import time
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding
 
 from repro.checkpoint import checkpointer
 from repro.configs import get_arch
 from repro.core import commplan, relayout, traffic as traffic_lib
 from repro.data.pipeline import ShardedLoader, SyntheticLM, ZipfNgramLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch import steps as steps_mod
 from repro.launch.steps import batch_specs, make_train_step
@@ -28,7 +30,7 @@ from repro.models import zoo
 from repro.models.lm import make_context
 from repro.optim import adamw
 from repro.parallel import sharding as sh
-from repro.runtime.fault_tolerance import RunConfig, run_training
+from repro.runtime.fault_tolerance import RunConfig, RunState, run_training
 
 
 def _migrate_moe_tree(tree, old_placement, new_placement):
@@ -166,11 +168,20 @@ def apply_relayout(params, opt, traffic_state, ctx, *, slots_per_lane=None,
     return params, opt, dataclasses.replace(ctx, placement=new), stats
 
 
+class TrainResult(NamedTuple):
+    params: Any
+    opt: Any
+    losses: list          # every step's loss, in order, replays included
+    run: RunState
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-moe-30b-a3b")
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-sized variant of the arch (CPU)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers (widths unchanged)")
     ap.add_argument("--engine", default="fused_hier",
                     help="dComm engine for the MoE shuffle (fused_flat | "
                          "fused_pipe | fused_hier | disagg | ragged), or "
@@ -196,10 +207,16 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--ckpt-every", type=int, default=50)
+    ap.add_argument("--ckpt-every", type=int, default=50,
+                    help="checkpoint cadence in steps, plus the last step; "
+                         "0 writes no checkpoint")
     ap.add_argument("--data", default="zipf", choices=["zipf", "uniform"])
     ap.add_argument("--capacity-factor", type=float, default=2.0)
     ap.add_argument("--inject-failure-at", type=int, default=None)
+    ap.add_argument("--max-restarts", type=int, default=3,
+                    help="step failures the runtime restarts from the last "
+                         "checkpoint before it re-raises (0: the first "
+                         "failure ends the run)")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--moe-stream", type=int, default=0,
                     help="moe_ffn/moe_tx families: layers per cross-layer "
@@ -238,6 +255,11 @@ def main(argv=None):
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers is not None:
+        print(f"depth cut: {cfg.name} {cfg.n_layers} -> {args.layers} layers",
+              flush=True)
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    enable_compile_cache()
     mesh = make_host_mesh()
     # --engine auto: the comm-path policy replans per layer at relayout
     # boundaries; until the first plan (cold EMA) every layer runs the
@@ -254,9 +276,13 @@ def main(argv=None):
     if args.calibrate:
         from repro.core import calibrate as calibrate_lib
         calibration = calibrate_lib.calibrate()
+        wire = (f"{calibration.wire_bw / 1e9:.1f} GB/s"
+                if calibration.wire_measured else
+                f"not measured (one device; assumed "
+                f"{calibration.wire_bw / 1e9:.1f} GB/s = stage / 4)")
         print(f"[calibrate] {calibration.platform}: "
               f"stage {calibration.stage_bw / 1e9:.1f} GB/s, "
-              f"wire {calibration.wire_bw / 1e9:.1f} GB/s, "
+              f"wire {wire}, "
               f"overhead {calibration.overhead_s * 1e6:.1f} us", flush=True)
     ctx = make_context(cfg, mesh, multi_pod=False, engine=base_engine,
                        capacity_factor=args.capacity_factor,
@@ -280,13 +306,8 @@ def main(argv=None):
 
     key = jax.random.PRNGKey(0)
     with mesh:
-        params = bundle.init(key)
-        pspecs = sh.param_specs(params, multi_pod=False,
-                                model_size=mesh.shape["model"],
-                                fsdp_experts=ctx.fsdp_experts)
-        params = jax.device_put(params, jax.tree.map(
-            lambda s: NamedSharding(mesh, s), pspecs,
-            is_leaf=lambda x: isinstance(x, P)))
+        params = sh.init_params_sharded(bundle.init, key, mesh,
+                                        fsdp_experts=ctx.fsdp_experts)
         opt = adamw.init(params)
         opt_cfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=max(5, args.steps // 20),
                                     total_steps=args.steps)
@@ -400,6 +421,7 @@ def main(argv=None):
             return {k: jax.device_put(v, bshard[k]) for k, v in host.items()}
 
         t_hist = []
+        losses = []
 
         def wrapped(params, opt, batch):
             t0 = time.perf_counter()
@@ -410,6 +432,7 @@ def main(argv=None):
             else:
                 params, opt, metrics = box["step_fn"](params, opt, batch)
             loss = float(metrics["loss"])
+            losses.append(loss)
             t_hist.append(time.perf_counter() - t0)
             n = len(t_hist)
             box["n"] += 1
@@ -470,7 +493,7 @@ def main(argv=None):
             # coincide the sidecar holds the post-reset lane-send EMA — a
             # resume must not feed Algorithm 1 loads measured under the
             # table the relayout just replaced.
-            if (box["traffic"] is not None
+            if (box["traffic"] is not None and args.ckpt_every
                     and (box["n"] % args.ckpt_every == 0
                          or box["n"] == args.steps)):
                 save_traffic_state(args.ckpt_dir, box["traffic"], box["n"])
@@ -479,11 +502,12 @@ def main(argv=None):
         rcfg = RunConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                          ckpt_every=args.ckpt_every,
                          inject_failure_at=args.inject_failure_at,
+                         max_restarts=args.max_restarts,
                          on_restart=on_restart)
         (params, opt), run = run_training(wrapped, (params, opt), batch_at, rcfg)
         print(f"done: {run.steps_run} steps, {run.restarts} restarts, "
               f"{run.straggler_events} straggler events")
-    return params, opt
+    return TrainResult(params, opt, losses, run)
 
 
 if __name__ == "__main__":

@@ -27,7 +27,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from repro.compat import axis_size, shard_map
+from repro.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dcomm import DcommConfig, _lane_index
@@ -365,7 +365,7 @@ def moe_decode_block(x: jax.Array, moe_p, *, mesh, placement: ExpertPlacement,
         my = jax.lax.axis_index(ep_axes[-1])
         if len(ep_axes) == 2:
             my = my + jax.lax.axis_index(ep_axes[0]) * (
-                placement.ep // axis_size(ep_axes[0]))
+                placement.ep // jax.lax.axis_size(ep_axes[0]))
         # masked dense compute over this lane's experts — every token through
         # every local expert, which is exactly the fused staging kernel's
         # (S=1, E_local, C=T, d) landed layout with all rows live

@@ -37,7 +37,9 @@ class AdamWState(NamedTuple):
 
 
 def init(params) -> AdamWState:
-    f32 = lambda t: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), t)
+    # zeros_like keeps each leaf's sharding: the moments live where
+    # their params do
+    f32 = lambda t: jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), t)
     master = jax.tree.map(lambda x: x.astype(jnp.float32), params)
     return AdamWState(jnp.zeros((), jnp.int32), f32(params), f32(params), master)
 

@@ -76,6 +76,18 @@ def shardings(specs, mesh):
                         is_leaf=lambda x: isinstance(x, P))
 
 
+def init_params_sharded(init, key, mesh, *, fsdp_experts: bool = False):
+    """Run ``init(key)`` under ``jit`` with ``out_shardings`` from
+    :func:`param_specs`, so every leaf is built in place on its own shard
+    (no full copy on the default device, no f32 transient outside the
+    fused init)."""
+    shapes = jax.eval_shape(init, key)
+    specs = param_specs(shapes, multi_pod="pod" in mesh.shape,
+                        model_size=mesh.shape["model"],
+                        fsdp_experts=fsdp_experts)
+    return jax.jit(init, out_shardings=shardings(specs, mesh))(key)
+
+
 def act_spec(multi_pod: bool, family: str) -> P:
     """Activation (B, S, d) spec between blocks: DP batch + SP sequence."""
     if multi_pod and family == "moe":

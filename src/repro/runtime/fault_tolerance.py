@@ -34,7 +34,7 @@ class InjectedFailure(RuntimeError):
 class RunConfig:
     total_steps: int
     ckpt_dir: str
-    ckpt_every: int = 50
+    ckpt_every: int = 50                   # 0: never checkpoint
     max_restarts: int = 3
     straggler_factor: float = 2.0
     straggler_window: int = 16
@@ -106,7 +106,8 @@ def run_training(step_fn: Callable, init_state: tuple, batch_at: Callable,
                 times.append(dt)
             step += 1
             run.steps_run += 1
-            if step % cfg.ckpt_every == 0 or step == cfg.total_steps:
+            if cfg.ckpt_every and (step % cfg.ckpt_every == 0
+                                   or step == cfg.total_steps):
                 checkpointer.wait(pending)
                 pending = checkpointer.save(cfg.ckpt_dir, (params, opt), step)
         except Exception as e:  # noqa: BLE001 — restart on ANY step failure

@@ -90,6 +90,13 @@ def _avals_like(tree):
         tree)
 
 
+def greedy(out):
+    """(logits, *rest) -> (greedy token ids, *rest), traced into the
+    serving executables so sampling never runs (or compiles) eagerly."""
+    logits, *rest = out
+    return (jnp.argmax(logits, -1).astype(jnp.int32), *rest)
+
+
 def _same_shardings(a, b) -> bool:
     return jax.tree.all(jax.tree.map(lambda x, y: x == y, a, b))
 
@@ -182,11 +189,14 @@ class _ServingBase:
     # ------------------------------------------- AOT-compiled executables ---
 
     def _prefill_callable(self) -> Callable:
+        """Prefill + greedy sampling in one executable: returns the first
+        token ids, never the (rows, vocab) logits."""
         if self.traffic is not None:
-            return lambda p, toks, tr, m: self.bundle.prefill(
-                p, {"tokens": toks}, self.max_len, traffic=tr, traffic_mask=m)
-        return lambda p, toks: self.bundle.prefill(
-            p, {"tokens": toks}, self.max_len)
+            return lambda p, toks, tr, m: greedy(self.bundle.prefill(
+                p, {"tokens": toks}, self.max_len, traffic=tr,
+                traffic_mask=m))
+        return lambda p, toks: greedy(self.bundle.prefill(
+            p, {"tokens": toks}, self.max_len))
 
     def _prefill_avals(self, rows: int, s: int):
         toks = jax.ShapeDtypeStruct((rows, s), jnp.int32)
@@ -218,16 +228,16 @@ class _ServingBase:
         exe = self._decode_exec.get(rows)
         if exe is None:
             t0 = time.perf_counter()
-            fn = lambda p, st, t: self.bundle.decode_step(p, st, t,
-                                                          self.max_len)
+            fn = lambda p, st, t: greedy(self.bundle.decode_step(
+                p, st, t, self.max_len))
             st_avals = _avals_like(state)
             tok = jax.ShapeDtypeStruct((rows,), jnp.int32)
             exe = jax.jit(fn).lower(params, st_avals, tok).compile()
             self.compile_count += 1
-            out_lg, out_st = exe.output_shardings
+            out_tok, out_st = exe.output_shardings
             in_st = jax.tree.map(lambda x: x.sharding, st_avals)
             if not _same_shardings(out_st, in_st):
-                exe = (jax.jit(fn, out_shardings=(out_lg, in_st))
+                exe = (jax.jit(fn, out_shardings=(out_tok, in_st))
                        .lower(params, st_avals, tok).compile())
                 self.compile_count += 1
             self._decode_exec[rows] = exe
@@ -249,6 +259,19 @@ class _ServingBase:
         if rows not in self._decode_exec:
             self.get_decode(params, self._prefill_state_avals(params, rows, s),
                             rows)
+
+    def _dry_prefill(self, params, rows: int, s: int):
+        """Compile the (rows × s) prefill and run it once on pad tokens,
+        results discarded (traffic state untouched).  An executable's first
+        run pays one-time costs of its own (tens of ms on XLA:CPU) that
+        must not land in the first request's TTFT.  Returns the state."""
+        exe = self.get_prefill(params, rows, s)
+        toks = jnp.asarray(np.full((rows, s), self.pad_id, np.int32))
+        args = (toks,) if self.traffic is None else (
+            toks, self.traffic, jnp.asarray(np.zeros((rows, s), bool)))
+        first, state, *_ = exe(params, *args)
+        jax.block_until_ready(first)
+        return state
 
     # ---------------------------------------------------- traffic + stats ---
 
@@ -314,14 +337,15 @@ class ServingEngine(_ServingBase):
 
     def warmup(self, params) -> float:
         """Pre-compile the full-wave prefill executable per bucket plus the
-        decode step; returns the seconds spent compiling.  Waves smaller
-        than ``max_batch`` still compile lazily on first occurrence (also
-        outside TTFT)."""
+        decode step, and run each once; returns the seconds spent.  Waves
+        smaller than ``max_batch`` still compile lazily on first occurrence
+        (also outside TTFT)."""
         t0 = time.perf_counter()
         rows = -(-self.max_batch // self._wave_mult) * self._wave_mult
-        for s in self.buckets:
-            self.get_prefill(params, rows, s)
+        states = [self._dry_prefill(params, rows, s) for s in self.buckets]
         self._warm_decode(params, rows, self.buckets[0])
+        tok = jnp.asarray(np.full((rows,), self.pad_id, np.int32))
+        jax.block_until_ready(self._decode_exec[rows](params, states[0], tok))
         return time.perf_counter() - t0
 
     def _form_wave(self) -> list[Request]:
@@ -352,19 +376,18 @@ class ServingEngine(_ServingBase):
         exe = self.get_prefill(params, bp, s)
         t0 = time.perf_counter()
         if self.traffic is not None:
-            logits, state, traffic = exe(params, batch, self.traffic,
-                                         jnp.asarray(valid))
+            first, state, traffic = exe(params, batch, self.traffic,
+                                        jnp.asarray(valid))
             self.traffic = _uncommitted(traffic)
             self._record_load()
         else:
-            logits, state = exe(params, batch)
-        jax.block_until_ready(logits)
+            first, state = exe(params, batch)
+        tok_np = np.asarray(first)
         end = time.perf_counter()
         for r in wave:
             r.ttft_s = end - r.submitted_at
 
         dec = self.get_decode(params, state, bp)
-        tok_np = np.asarray(jnp.argmax(logits, -1), np.int32)
         live = np.ones(b, bool)
         steps = max(r.max_new for r in wave)
         for step in range(steps):
@@ -378,8 +401,8 @@ class ServingEngine(_ServingBase):
                     r.done = True
             if not live.any() or step == steps - 1:
                 break
-            logits, state = dec(params, state, jnp.asarray(tok_np))
-            tok_np = np.asarray(jnp.argmax(logits, -1), np.int32)
+            tok, state = dec(params, state, jnp.asarray(tok_np))
+            tok_np = np.asarray(tok)
         for r in wave:
             r.done = True
         self.finished.extend(wave)
@@ -493,16 +516,22 @@ class ContinuousServingEngine(_ServingBase):
 
     def warmup(self, params) -> float:
         """AOT-compile every (admit-chunk × bucket) prefill executable, the
-        pool decode step and the slot-insert scatter; returns seconds spent.
-        After warmup, ``compile_count`` must stay flat under any admission
-        pattern whose prompts fit the buckets (compilation-counter test)."""
+        pool decode step and the slot-insert scatter, and run each once;
+        returns seconds spent.  After warmup, ``compile_count`` must stay
+        flat under any admission pattern whose prompts fit the buckets
+        (compilation-counter test)."""
         t0 = time.perf_counter()
         self._ensure_pool()
         for s in self.buckets:
-            self.get_prefill(params, self.admit_chunk, s)
-        self._get_insert(self._prefill_state_avals(params, self.admit_chunk,
-                                                   self.buckets[0]))
-        self.get_decode(params, self._state, self.max_batch)
+            new = self._dry_prefill(params, self.admit_chunk, s)
+        # every slot id out of range: the scatter drops all rows and the
+        # pool comes back unchanged
+        none = jnp.asarray(np.full((self.admit_chunk,), self.max_batch,
+                                   np.int32))
+        self._state = self._get_insert(new)(self._state, new, none)
+        dec = self.get_decode(params, self._state, self.max_batch)
+        jax.block_until_ready(dec(params, self._state,
+                                  jnp.asarray(self._tok)))
         return time.perf_counter() - t0
 
     # --------------------------------------------------------- scheduling ---
@@ -549,15 +578,14 @@ class ContinuousServingEngine(_ServingBase):
             self._ensure_pool()
             t_batch = jnp.asarray(toks)
             if self.traffic is not None:
-                logits, new_state, traffic = exe(
+                first, new_state, traffic = exe(
                     params, t_batch, self.traffic, jnp.asarray(valid))
                 self.traffic = _uncommitted(traffic)
                 self._record_load()
             else:
-                logits, new_state = exe(params, t_batch)
-            jax.block_until_ready(logits)
+                first, new_state = exe(params, t_batch)
+            first = np.asarray(first)
             end = time.perf_counter()
-            first = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
             # pad lanes point at slot id max_batch -> dropped by the scatter
             slot_arr = np.full((self.admit_chunk,), self.max_batch, np.int32)
             for j, r in enumerate(reqs):
@@ -586,9 +614,8 @@ class ContinuousServingEngine(_ServingBase):
         self._ensure_pool()
         dec = self.get_decode(params, self._state, self.max_batch)
         t0 = time.perf_counter()
-        logits, self._state = dec(params, self._state,
-                                  jnp.asarray(self._tok))
-        tok = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+        tok, self._state = dec(params, self._state, jnp.asarray(self._tok))
+        tok = np.asarray(tok)
         self.decode_s += time.perf_counter() - t0
         self.decode_steps += 1
         self.decode_tokens += len(occupied)

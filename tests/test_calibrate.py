@@ -2,6 +2,7 @@
 
 import dataclasses
 
+import jax
 import pytest
 
 from repro.core import calibrate, commplan, pipesim
@@ -20,7 +21,9 @@ def test_rates_positive_and_finite(table):
     assert table.platform and table.payload_bytes > 0
     d = table.as_dict()
     assert set(d) == {"stage_bw", "wire_bw", "overhead_s", "platform",
-                      "payload_bytes"}
+                      "payload_bytes", "wire_measured"}
+    # one device has no wire to time: the table must say so
+    assert table.wire_measured == (len(jax.devices()) > 1)
 
 
 def test_apply_threads_into_linkcosts_and_pipesim(table):

@@ -164,12 +164,12 @@ def test_pallas_flash_matches_naive(offset, window, hq, hkv):
     v = jax.random.normal(ks[2], (B, Sk, hkv, hd))
     qp = jnp.arange(Sq) + offset
     kp = jnp.arange(Sk)
-    out = pallas_flash(q, k, v, qp, kp, True, window, 16, 16)
+    out = pallas_flash(q, k, v, qp, kp, True, window, 16, 16, True)
     expect = naive(q, k, v, qp, kp, True, window)
     assert float(jnp.max(jnp.abs(out - expect))) < 2e-5
 
     f = lambda q, k, v: pallas_flash(q, k, v, qp, kp, True, window,
-                                     16, 16).sum()
+                                     16, 16, True).sum()
     n = lambda q, k, v: naive(q, k, v, qp, kp, True, window).sum()
     gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
     gn = jax.grad(n, argnums=(0, 1, 2))(q, k, v)

@@ -11,34 +11,35 @@ from repro.kernels.segment_gather import segment_gather
 from repro.kernels.segment_scatter_add import segment_scatter_add
 
 
-@pytest.mark.parametrize("t,r,d,bd,dtype", [
-    (37, 16, 256, 128, jnp.float32),
-    (64, 64, 512, 512, jnp.bfloat16),
-    (8, 128, 128, 64, jnp.float32),
-    (5, 3, 256, 256, jnp.bfloat16),
+@pytest.mark.parametrize("t,r,d,br,dtype", [
+    (37, 16, 256, 8, jnp.float32),
+    (64, 64, 512, 32, jnp.bfloat16),
+    (8, 128, 128, 16, jnp.float32),
+    (5, 3, 256, 32, jnp.bfloat16),
 ])
-def test_segment_gather_sweep(t, r, d, bd, dtype):
+def test_segment_gather_sweep(t, r, d, br, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     src = jax.random.normal(ks[0], (t, d)).astype(dtype)
     idx = jax.random.randint(ks[1], (r,), -1, t).astype(jnp.int32)
-    out = segment_gather(src, idx, block_d=bd, interpret=True)
+    out = segment_gather(src, idx, block_r=br, interpret=True)
     expect = ref.segment_gather_ref(src, idx)
     assert out.dtype == dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), atol=0)
 
 
-@pytest.mark.parametrize("r,out_rows,d,bd,dtype", [
-    (8, 5, 256, 128, jnp.float32),
-    (32, 8, 512, 512, jnp.float32),
-    (16, 4, 128, 64, jnp.bfloat16),
+@pytest.mark.parametrize("r,out_rows,d,br,dtype", [
+    (8, 5, 256, 8, jnp.float32),
+    (32, 8, 512, 32, jnp.float32),
+    (16, 4, 128, 32, jnp.bfloat16),
+    (45, 6, 256, 8, jnp.float32),         # ragged tail, many revisits
 ])
-def test_segment_scatter_add_sweep(r, out_rows, d, bd, dtype):
+def test_segment_scatter_add_sweep(r, out_rows, d, br, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     src = jax.random.normal(ks[0], (r, d)).astype(dtype)
     dst = jax.random.randint(ks[1], (r,), -1, out_rows).astype(jnp.int32)
     gates = jax.random.uniform(ks[2], (r,))
-    out = segment_scatter_add(src, dst, gates, out_rows, block_d=bd,
+    out = segment_scatter_add(src, dst, gates, out_rows, block_r=br,
                               interpret=True)
     expect = ref.segment_scatter_add_ref(src, dst, gates, out_rows)
     tol = 1e-6 if dtype == jnp.float32 else 3e-2

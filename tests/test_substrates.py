@@ -157,6 +157,29 @@ def test_fault_tolerant_restart(tmp_path):
     assert int(params) == 10   # restarted from step 4, replayed to 10
 
 
+def test_no_restarts_reraises_and_no_checkpoints(tmp_path):
+    """max_restarts=0: the first step failure ends the run (it is never
+    retried into a run that exits 0); ckpt_every=0 writes nothing."""
+    from repro.runtime.fault_tolerance import (InjectedFailure, RunConfig,
+                                               run_training)
+
+    def step_fn(params, opt, batch):
+        return params + 1, opt, {"loss": jnp.float32(1.0)}
+
+    init = (jnp.int32(0), jnp.int32(0))
+    with pytest.raises(InjectedFailure):
+        run_training(step_fn, init, lambda s: None,
+                     RunConfig(total_steps=4, ckpt_dir=str(tmp_path),
+                               inject_failure_at=1, max_restarts=0),
+                     log=lambda *a: None)
+    (params, _), run = run_training(
+        step_fn, init, lambda s: None,
+        RunConfig(total_steps=4, ckpt_dir=str(tmp_path / "none"),
+                  ckpt_every=0), log=lambda *a: None)
+    assert int(params) == 4 and run.restarts == 0
+    assert not (tmp_path / "none").exists()
+
+
 def test_elastic_relayout():
     from repro.core.routing import ExpertPlacement
     from repro.runtime.elastic import relayout_expert_weights
