@@ -349,6 +349,7 @@ def pipe_geometry(t: int, k: int, d: int, itemsize: int,
     return cap, s
 
 
+@jax.named_scope("moe.dispatch")
 def _pipe_slice_plan(x: jax.Array, A: jax.Array, gates: jax.Array,
                      placement: ExpertPlacement, cfg: DcommConfig):
     """Build the flat plan with capacity rounded so it splits into S slices."""
@@ -359,6 +360,7 @@ def _pipe_slice_plan(x: jax.Array, A: jax.Array, gates: jax.Array,
     return plan, sliced, cap, s
 
 
+@jax.named_scope("moe.dispatch")
 def pipe_issue(x: jax.Array, src_slice: jax.Array, placement: ExpertPlacement,
                cfg: DcommConfig) -> jax.Array:
     """Producer half of one slice: descriptor gather stages it, the tiled
@@ -375,6 +377,7 @@ def pipe_issue(x: jax.Array, src_slice: jax.Array, placement: ExpertPlacement,
     return buf.reshape(ep, e_local, cs, d)
 
 
+@jax.named_scope("moe.combine")
 def pipe_return_issue(out_slice: jax.Array, placement: ExpertPlacement,
                       cfg: DcommConfig) -> jax.Array:
     """Wire half of one slice's combine: reverse tiled exchange of the expert
@@ -386,6 +389,7 @@ def pipe_return_issue(out_slice: jax.Array, placement: ExpertPlacement,
     return buf.reshape(ep * e_local * cs, d)
 
 
+@jax.named_scope("moe.combine")
 def pipe_return_consume(y: jax.Array, returned: jax.Array,
                         src_slice: jax.Array, gate_slice: jax.Array,
                         t: int) -> jax.Array:

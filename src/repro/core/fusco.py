@@ -41,6 +41,7 @@ from repro.layers.attention import gqa_project
 from repro.layers.common import apply_rope, rms_norm
 
 
+@jax.named_scope("moe.expert_ffn")
 def swiglu_experts(rows: jax.Array, w1: jax.Array, w3: jax.Array,
                    w2: jax.Array) -> jax.Array:
     """Grouped SwiGLU FFN consuming the landed buffer in place.
@@ -55,6 +56,7 @@ def swiglu_experts(rows: jax.Array, w1: jax.Array, w3: jax.Array,
     return kops.fused_swiglu(rows, w1, w3, w2)
 
 
+@jax.named_scope("moe.dispatch")
 def dispatch(x, A, gates, placement: ExpertPlacement, cfg: DcommConfig,
              assignment=None) -> DispatchResult:
     if cfg.engine == "fused_flat":
@@ -73,6 +75,7 @@ def dispatch(x, A, gates, placement: ExpertPlacement, cfg: DcommConfig,
     raise ValueError(f"unknown engine {cfg.engine!r}")
 
 
+@jax.named_scope("moe.combine")
 def combine(expert_out, res: DispatchResult, placement, cfg: DcommConfig,
             gates=None) -> jax.Array:
     if cfg.engine == "fused_flat":
@@ -120,8 +123,9 @@ def moe_shuffle_ffn(x: jax.Array, w_router: jax.Array, w1: jax.Array,
     are this lane's expert slices (E_local, d, f)/(E_local, f, d); the router
     weight is replicated.
     """
-    logits = router_logits(x, w_router)
-    A, gates = top_k_routing(logits, top_k, normalize=norm_topk)
+    with jax.named_scope("moe.route"):
+        logits = router_logits(x, w_router)
+        A, gates = top_k_routing(logits, top_k, normalize=norm_topk)
     return shuffle_ffn(x, A, gates.astype(x.dtype), w1, w3, w2, placement,
                        cfg, assignment)
 

@@ -145,6 +145,7 @@ def _flash_fwd_pallas(q, k, v, q_positions, k_positions, causal, window,
         out_shape=[jax.ShapeDtypeStruct((b, hkv, g, sq, hd), q.dtype),
                    jax.ShapeDtypeStruct((b, hkv, g, sq, 1), jnp.float32)],
         interpret=interpret,
+        name="_flash_fwd_pallas",
     )
     o, lse = fn(qmn, qmx, kmn, kmx, qp, kp, qr, kr, vr)
     out = o.reshape(b, hq, sq, hd).transpose(0, 2, 1, 3)

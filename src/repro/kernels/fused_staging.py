@@ -109,5 +109,6 @@ def fused_swiglu_pallas(x: jax.Array, w1: jax.Array, w3: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, e, c, d), x.dtype),
         interpret=interpret,
+        name="fused_swiglu_pallas",
     )
     return fn(counts.astype(jnp.int32), x, w1, w3, w2)

@@ -84,5 +84,6 @@ def grouped_matmul(x: jax.Array, w: jax.Array, counts: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, c, f), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )
     return fn(counts.astype(jnp.int32), x, w)

@@ -81,6 +81,7 @@ def segment_gather(src: jax.Array, idx: jax.Array, *, block_r: int = 32,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem),
         interpret=interpret,
+        name="segment_gather",
     )
     out = fn(idx, src)
     return out if rp == r else out[:r]

@@ -74,5 +74,6 @@ def segment_scatter_add(src: jax.Array, dst: jax.Array, gates: jax.Array,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=vmem),
         interpret=interpret,
+        name="segment_scatter_add",
     )
     return fn(dst, gates, src)

@@ -85,17 +85,18 @@ def moe_block(x: jax.Array, moe_params, *, mesh, placement: ExpertPlacement,
             w2 = jax.lax.all_gather(w2, "data", axis=2, tiled=True)
         b, s, d = xl.shape
         xt = xl.reshape(b * s, d)
-        logits = router_logits(xt, wr)
-        A, gates = top_k_routing(logits, top_k, normalize=norm_topk)
-        assignment = None
-        if tr is not None:
-            tr = traffic_lib.observe(tr, A, placement, _lane_index(dcfg, placement),
-                                     decay=traffic_decay, axis_names=axis_names,
-                                     valid=None if mask is None
-                                     else mask.reshape(b * s))
-            if dcfg.engine == "fused_hier" and dcfg.use_balancer:
-                assignment = balancer_lib.algorithm1_groups(
-                    traffic_lib.balancer_loads(tr, placement))
+        with jax.named_scope("moe.route"):
+            logits = router_logits(xt, wr)
+            A, gates = top_k_routing(logits, top_k, normalize=norm_topk)
+            assignment = None
+            if tr is not None:
+                tr = traffic_lib.observe(
+                    tr, A, placement, _lane_index(dcfg, placement),
+                    decay=traffic_decay, axis_names=axis_names,
+                    valid=None if mask is None else mask.reshape(b * s))
+                if dcfg.engine == "fused_hier" and dcfg.use_balancer:
+                    assignment = balancer_lib.algorithm1_groups(
+                        traffic_lib.balancer_loads(tr, placement))
         y = fusco.shuffle_ffn(xt, A, gates.astype(xt.dtype), w1[0], w3[0],
                               w2[0], placement, dcfg, assignment)
         return y.reshape(b, s, d), tr
@@ -350,35 +351,43 @@ def moe_decode_block(x: jax.Array, moe_p, *, mesh, placement: ExpertPlacement,
     dp = data_axes if x.shape[0] % dsz == 0 and x.shape[0] >= dsz else ()
 
     def inner(xl, wr, w1, w3, w2):
-        if fsdp:
-            # local layout (EP_loc=1, E_local, d, f_shard)
-            w1 = jax.lax.all_gather(w1, "data", axis=3, tiled=True)
-            w3 = jax.lax.all_gather(w3, "data", axis=3, tiled=True)
-            w2 = jax.lax.all_gather(w2, "data", axis=2, tiled=True)
-        b, s, d = xl.shape
-        xt = xl.reshape(b * s, d)
-        logits = router_logits(xt, wr)
-        A, gates = top_k_routing(logits, top_k, norm_topk)
-        replica = balanced_replica_choice(A, placement)
-        lane = placement.lane_of_expert(A, replica)
-        eloc = placement.local_expert_index(A, replica)
-        my = jax.lax.axis_index(ep_axes[-1])
-        if len(ep_axes) == 2:
-            my = my + jax.lax.axis_index(ep_axes[0]) * (
-                placement.ep // jax.lax.axis_size(ep_axes[0]))
-        # masked dense compute over this lane's experts — every token through
-        # every local expert, which is exactly the fused staging kernel's
-        # (S=1, E_local, C=T, d) landed layout with all rows live
-        rows = jnp.broadcast_to(xt[None, None],
-                                (1, w1.shape[1]) + xt.shape)
-        out_e = kops.fused_swiglu(rows, w1[0], w3[0], w2[0])[0]
-        out_e = jnp.moveaxis(out_e, 0, 1)                # (T, E_local, d)
-        mask = (lane == my)[..., None] & (
-            eloc[..., None] == jnp.arange(placement.experts_per_lane))
-        w = (mask * gates[..., None]).sum(axis=1).astype(out_e.dtype)  # (T, E_local)
-        y = jnp.einsum("ted,te->td", out_e, w)
-        y = jax.lax.psum(y, ep_axes)
-        return y.reshape(b, s, d)
+        # this lane's experts: a view of the layer's slice of the stacked
+        # weights, taken outside the block's scope, since the copy it can
+        # cost belongs to the layer scan (layers.scan_io in a trace)
+        w1, w3, w2 = w1[0], w3[0], w2[0]
+        with jax.named_scope("moe.decode"):
+            if fsdp:
+                # local layout (E_local, d, f_shard)
+                w1 = jax.lax.all_gather(w1, "data", axis=2, tiled=True)
+                w3 = jax.lax.all_gather(w3, "data", axis=2, tiled=True)
+                w2 = jax.lax.all_gather(w2, "data", axis=1, tiled=True)
+            b, s, d = xl.shape
+            xt = xl.reshape(b * s, d)
+            with jax.named_scope("moe.route"):
+                logits = router_logits(xt, wr)
+                A, gates = top_k_routing(logits, top_k, norm_topk)
+                replica = balanced_replica_choice(A, placement)
+                lane = placement.lane_of_expert(A, replica)
+                eloc = placement.local_expert_index(A, replica)
+            my = jax.lax.axis_index(ep_axes[-1])
+            if len(ep_axes) == 2:
+                my = my + jax.lax.axis_index(ep_axes[0]) * (
+                    placement.ep // jax.lax.axis_size(ep_axes[0]))
+            # masked dense compute over this lane's experts — every token
+            # through every local expert, which is exactly the fused staging
+            # kernel's (S=1, E_local, C=T, d) landed layout, all rows live
+            with jax.named_scope("moe.expert_ffn"):
+                rows = jnp.broadcast_to(xt[None, None],
+                                        (1, w1.shape[0]) + xt.shape)
+                out_e = kops.fused_swiglu(rows, w1, w3, w2)[0]
+            out_e = jnp.moveaxis(out_e, 0, 1)                # (T, E_local, d)
+            mask = (lane == my)[..., None] & (
+                eloc[..., None] == jnp.arange(placement.experts_per_lane))
+            w = (mask * gates[..., None]).sum(axis=1).astype(
+                out_e.dtype)                                 # (T, E_local)
+            y = jnp.einsum("ted,te->td", out_e, w)
+            y = jax.lax.psum(y, ep_axes)
+            return y.reshape(b, s, d)
 
     x_spec = P(dp or None, None, None)
     if fsdp:

@@ -12,6 +12,7 @@ all-to-all is degenerate — the paper's evaluation targets training and TTFT
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 from typing import Any, NamedTuple
@@ -277,6 +278,14 @@ def _engine_runs(engines):
     return runs
 
 
+def _moe_scope(cfg: ArchConfig, name: str):
+    """Named scope for the MoE branch's norm or residual add (nothing for
+    a dense MLP), so a layer body holds no unnamed work of its own."""
+    if cfg.family == "moe":
+        return jax.named_scope(name)
+    return contextlib.nullcontext()
+
+
 def _scan_layers(layer_fn, h, layers, cfg: ArchConfig, remat: bool):
     """Scan over layers; hybrid archs run one scan per global/SWA segment.
     layer_fn(h, lp, is_global) -> (h, ys)."""
@@ -286,7 +295,8 @@ def _scan_layers(layer_fn, h, layers, cfg: ArchConfig, remat: bool):
             seg = jax.tree.map(lambda x: x[a:b], layers)
             body = partial(layer_fn, is_global=gflag)
             body = jax.checkpoint(body) if remat else body
-            h, ys = jax.lax.scan(body, h, seg)
+            with jax.named_scope("layers"):
+                h, ys = jax.lax.scan(body, h, seg)
             ys_all.append(ys)
         if ys_all and ys_all[0] is not None and jax.tree.leaves(ys_all[0]):
             ys = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *ys_all)
@@ -295,7 +305,8 @@ def _scan_layers(layer_fn, h, layers, cfg: ArchConfig, remat: bool):
         return h, ys
     body = partial(layer_fn, is_global=False)
     body = jax.checkpoint(body) if remat else body
-    return jax.lax.scan(body, h, layers)
+    with jax.named_scope("layers"):
+        return jax.lax.scan(body, h, layers)
 
 
 def _tx_stack(params, h, positions, ctx: ModelContext, traffic=None,
@@ -345,7 +356,8 @@ def _tx_stack(params, h, positions, ctx: ModelContext, traffic=None,
 
     body = jax.checkpoint(block_fn) if ctx.remat else block_fn
     xs = blocks if traffic is None else (blocks, jax.tree.map(reblock, traffic))
-    h, (new_traffic, kv) = jax.lax.scan(body, h, xs)
+    with jax.named_scope("layers"):
+        h, (new_traffic, kv) = jax.lax.scan(body, h, xs)
     h = rms_norm(h, params["final_norm"].astype(cd))
     unblock = lambda a: a.reshape((L,) + a.shape[2:])
     if traffic is not None:
@@ -429,7 +441,8 @@ def forward_hidden(params, inputs, positions, ctx: ModelContext,
         body = jax.checkpoint(block_fn) if ctx.remat else block_fn
         xs = blocks if traffic is None else (
             blocks, jax.tree.map(reblock, traffic))
-        h, new_traffic = jax.lax.scan(body, h, xs)
+        with jax.named_scope("layers"):
+            h, new_traffic = jax.lax.scan(body, h, xs)
         h = rms_norm(h, params["final_norm"].astype(cd))
         if traffic is None:
             return h
@@ -446,34 +459,37 @@ def forward_hidden(params, inputs, positions, ctx: ModelContext,
                           if x.dtype in (jnp.float32, jnp.bfloat16) else x, lp)
         if cfg.family in ("dense", "moe", "vlm", "hybrid"):
             use_tp = ctx.tp_eligible()
-            if cfg.family == "hybrid":
-                x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
-                mix, _, _ = hymba_mixer(
-                    x, lp, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.hd, rope_theta=cfg.rope_theta,
-                    positions=positions, window=cfg.window,
-                    is_global=is_global, ssm_args=ssm_args,
-                    shard_ctx=(ctx.mesh, ctx.data_axes, "model"),
-                    mid_spec=ctx.mid_spec())
-            elif use_tp:
-                from repro.parallel.tp_blocks import megatron_attention
-                x = rms_norm(h, lp["ln1"])     # stays sequence-sharded
-                mix = megatron_attention(
-                    x, lp["attn"], mesh=ctx.mesh, data_axes=ctx.data_axes,
-                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                    rope_theta=cfg.rope_theta, positions=positions,
-                    causal=True, window=cfg.window, qk_norm=cfg.qk_norm)
-            else:
-                x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
-                mix = attention_block(
-                    x, lp["attn"], n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.hd, rope_theta=cfg.rope_theta,
-                    positions=positions, causal=True, window=cfg.window,
-                    qk_norm=cfg.qk_norm, mrope_sections=cfg.mrope_sections,
-                    shard_ctx=(ctx.mesh, ctx.data_axes, "model"))
-            h = ctx.constrain(h + mix)
+            with jax.named_scope("attention"):
+                if cfg.family == "hybrid":
+                    x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
+                    mix, _, _ = hymba_mixer(
+                        x, lp, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.hd, rope_theta=cfg.rope_theta,
+                        positions=positions, window=cfg.window,
+                        is_global=is_global, ssm_args=ssm_args,
+                        shard_ctx=(ctx.mesh, ctx.data_axes, "model"),
+                        mid_spec=ctx.mid_spec())
+                elif use_tp:
+                    from repro.parallel.tp_blocks import megatron_attention
+                    x = rms_norm(h, lp["ln1"])     # stays sequence-sharded
+                    mix = megatron_attention(
+                        x, lp["attn"], mesh=ctx.mesh, data_axes=ctx.data_axes,
+                        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.hd, rope_theta=cfg.rope_theta,
+                        positions=positions, causal=True, window=cfg.window,
+                        qk_norm=cfg.qk_norm)
+                else:
+                    x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
+                    mix = attention_block(
+                        x, lp["attn"], n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.hd, rope_theta=cfg.rope_theta,
+                        positions=positions, causal=True, window=cfg.window,
+                        qk_norm=cfg.qk_norm, mrope_sections=cfg.mrope_sections,
+                        shard_ctx=(ctx.mesh, ctx.data_axes, "model"))
+                h = ctx.constrain(h + mix)
             if cfg.family == "moe":
-                x = rms_norm(h, lp["ln2"])     # island is sequence-sharded
+                with jax.named_scope("moe.route"):
+                    x = rms_norm(h, lp["ln2"])  # island is sequence-sharded
                 y = moe_block(x, lp["moe"], mesh=ctx.mesh,
                               placement=ctx.placement, dcfg=dcfg,
                               top_k=cfg.moe.top_k, data_axes=ctx.data_axes,
@@ -495,7 +511,8 @@ def forward_hidden(params, inputs, positions, ctx: ModelContext,
                 w = jax.lax.with_sharding_constraint(
                     x @ lp["mlp"]["w_up"], ctx.mid_spec())
                 y = (jax.nn.silu(u) * w) @ lp["mlp"]["w_down"]
-            h = ctx.constrain(h + y)
+            with _moe_scope(cfg, "moe.combine"):
+                h = ctx.constrain(h + y)
         elif cfg.family == "ssm":
             x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
             y, _ = mamba2_mixer(x, lp["ssm"], mid_spec=ctx.mid_spec(),
@@ -522,7 +539,8 @@ def forward_hidden(params, inputs, positions, ctx: ModelContext,
                 dedup=ctx.dcfg.dedup and eng == "fused_flat")
             body = partial(layer_fn, is_global=False, dcfg=dcfg_run)
             body = jax.checkpoint(body) if ctx.remat else body
-            h, ys = jax.lax.scan(body, h, seg)
+            with jax.named_scope("layers"):
+                h, ys = jax.lax.scan(body, h, seg)
             ys_all.append(ys)
         if ys_all and ys_all[0] is not None and jax.tree.leaves(ys_all[0]):
             new_traffic = jax.tree.map(
@@ -664,31 +682,40 @@ def decode_step(params, state: DecodeState, inputs, ctx: ModelContext,
                           if x.dtype in (jnp.float32, jnp.bfloat16) else x, lp)
         new_kv, new_ssm = kv_l, ssm_l
         if cfg.family in ("dense", "moe", "vlm"):
-            x = rms_norm(h, lp["ln1"])
-            q, k, v = attn_lib.gqa_project(
-                x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
-                cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                lp["attn"].get("q_norm") if cfg.qk_norm else None,
-                lp["attn"].get("k_norm") if cfg.qk_norm else None)
-            if cfg.mrope_sections:
-                q = apply_mrope(q, positions, cfg.mrope_sections, cfg.rope_theta)
-                k = apply_mrope(k, positions, cfg.mrope_sections, cfg.rope_theta)
-            else:
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
-            cache = KVCache(kv_l["k"], kv_l["v"], pos, max_len)
-            cache = cache_update(cache, k, v)
-            a = decode_attention(q, cache)
-            new_kv = {"k": cache.k, "v": cache.v}
-            mix = a.reshape(b, 1, cfg.n_heads * cfg.hd) @ lp["attn"]["wo"]
-            h = h + mix
-            x = rms_norm(h, lp["ln2"])
+            with jax.named_scope("attention"):
+                x = rms_norm(h, lp["ln1"])
+                q, k, v = attn_lib.gqa_project(
+                    x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
+                    cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+                    lp["attn"].get("q_norm") if cfg.qk_norm else None,
+                    lp["attn"].get("k_norm") if cfg.qk_norm else None)
+                if cfg.mrope_sections:
+                    q = apply_mrope(q, positions, cfg.mrope_sections,
+                                    cfg.rope_theta)
+                    k = apply_mrope(k, positions, cfg.mrope_sections,
+                                    cfg.rope_theta)
+                else:
+                    q = apply_rope(q, positions, cfg.rope_theta)
+                    k = apply_rope(k, positions, cfg.rope_theta)
+                cache = KVCache(kv_l["k"], kv_l["v"], pos, max_len)
+                cache = cache_update(cache, k, v)
+                a = decode_attention(q, cache)
+                new_kv = {"k": cache.k, "v": cache.v}
+                mix = a.reshape(b, 1, cfg.n_heads * cfg.hd) @ lp["attn"]["wo"]
+                h = h + mix
             if cfg.family == "moe":
+                # the block opens moe.decode itself, after its view of the
+                # layer's expert weights (see layers/moe.moe_decode_block)
+                with jax.named_scope("moe.decode"):
+                    x = rms_norm(h, lp["ln2"])
                 y = _moe_decode_block(x, lp["moe"], ctx)
+                with jax.named_scope("moe.decode"):
+                    h = h + y
             else:
+                x = rms_norm(h, lp["ln2"])
                 y = jax.nn.silu(x @ lp["mlp"]["w_gate"]) * (x @ lp["mlp"]["w_up"])
                 y = y @ lp["mlp"]["w_down"]
-            h = h + y
+                h = h + y
         elif cfg.family == "moe_tx":
             # parallel block: attention AND the MoE branch both read the
             # block input h (what makes the attention tail-independent in
@@ -739,9 +766,12 @@ def decode_step(params, state: DecodeState, inputs, ctx: ModelContext,
           jax.tree.map(lambda _: jnp.zeros((cfg.n_layers,)), flags),
           state.ssm if state.ssm is not None else
           jax.tree.map(lambda _: jnp.zeros((cfg.n_layers,)), flags))
-    h, (new_kv, new_ssm) = jax.lax.scan(layer_fn, h, xs)
-    h = rms_norm(h, params["final_norm"].astype(cd))
-    logits = (h[:, 0] @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    with jax.named_scope("layers"):
+        h, (new_kv, new_ssm) = jax.lax.scan(layer_fn, h, xs)
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h, params["final_norm"].astype(cd))
+        logits = (h[:, 0] @ params["lm_head"].astype(cd)).astype(
+            jnp.float32)
     return logits, DecodeState(
         new_kv if state.kv is not None else None,
         new_ssm if state.ssm is not None else None,
@@ -821,77 +851,53 @@ def prefill(params, inputs, positions, ctx: ModelContext, max_len: int,
         lp = jax.tree.map(lambda x: x.astype(cd)
                           if x.dtype in (jnp.float32, jnp.bfloat16) else x, lp)
         kv_out = ssm_out = None
-        # explicit-TP is a train-side win (collective-bound); prefill is
-        # memory-bound and measured ~15% worse under it — keep sharded flash.
-        if False and cfg.family in ("dense", "moe", "vlm", "hybrid") and ctx.tp_eligible():
-            from repro.parallel.tp_blocks import megatron_attention, megatron_mlp
-            x = rms_norm(h, lp["ln1"])
-            mix, k, v = megatron_attention(
-                x, lp["attn"], mesh=ctx.mesh, data_axes=ctx.data_axes,
-                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
-                rope_theta=cfg.rope_theta, positions=positions, causal=True,
-                window=cfg.window, qk_norm=cfg.qk_norm, return_kv=True)
-            if s >= cap:
-                ks_ = jnp.roll(k[:, -cap:], s % cap, axis=1)
-                vs_ = jnp.roll(v[:, -cap:], s % cap, axis=1)
-            else:
-                padw = ((0, 0), (0, cap - s), (0, 0), (0, 0))
-                ks_, vs_ = jnp.pad(k, padw), jnp.pad(v, padw)
-            kv_out = {"k": ks_, "v": vs_}
-            h = ctx.constrain(h + mix)
-            if cfg.family == "moe":
-                x = rms_norm(h, lp["ln2"])     # island is sequence-sharded
-                y = moe_block(x, lp["moe"], mesh=ctx.mesh, placement=ctx.placement,
-                              dcfg=ctx.dcfg, top_k=cfg.moe.top_k,
-                              data_axes=ctx.data_axes, norm_topk=cfg.moe.norm_topk,
-                              fsdp=ctx.fsdp_experts)
-            else:
-                x = rms_norm(h, lp["ln2"])
-                y = megatron_mlp(x, lp["mlp"], mesh=ctx.mesh,
-                                 data_axes=ctx.data_axes)
-            h = ctx.constrain(h + y)
-        elif cfg.family in ("dense", "moe", "vlm", "hybrid"):
-            x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
-            if cfg.family == "hybrid":
-                mix, _, st2 = hymba_mixer(
-                    x, lp, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.hd, rope_theta=cfg.rope_theta,
-                    positions=positions, window=cfg.window,
-                    is_global=is_global, ssm_args=ssm_args,
-                    shard_ctx=(ctx.mesh, ctx.data_axes, "model"),
-                    mid_spec=ctx.mid_spec())
-                ssm_out = {"state": st2.ssd, "conv": st2.conv}
-                # caches for attention branch recomputed below
-                q, k, v = attn_lib.gqa_project(
-                    x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
-                    cfg.n_heads, cfg.n_kv_heads, cfg.hd)
-            else:
-                mix = attention_block(
-                    x, lp["attn"], n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.hd, rope_theta=cfg.rope_theta,
-                    positions=positions, causal=True, window=cfg.window,
-                    qk_norm=cfg.qk_norm, mrope_sections=cfg.mrope_sections,
-                    shard_ctx=(ctx.mesh, ctx.data_axes, "model"))
-                q, k, v = attn_lib.gqa_project(
-                    x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
-                    cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                    lp["attn"].get("q_norm") if cfg.qk_norm else None,
-                    lp["attn"].get("k_norm") if cfg.qk_norm else None)
-            if cfg.mrope_sections:
-                k = apply_mrope(k, positions, cfg.mrope_sections, cfg.rope_theta)
-            else:
-                k = apply_rope(k, positions, cfg.rope_theta)
-            # last `cap` positions fill the ring cache; position p -> slot
-            # p % cap, so the packed window is rolled by s % cap.
-            if s >= cap:
-                ks_ = jnp.roll(k[:, -cap:], s % cap, axis=1)
-                vs_ = jnp.roll(v[:, -cap:], s % cap, axis=1)
-            else:
-                pad = ((0, 0), (0, cap - s), (0, 0), (0, 0))
-                ks_, vs_ = jnp.pad(k, pad), jnp.pad(v, pad)
-            kv_out = {"k": ks_, "v": vs_}
-            h = ctx.constrain(h + mix)
-            x = ctx.gather_seq(rms_norm(h, lp["ln2"]))
+        # no explicit-TP blocks here: they win in training (collective-bound)
+        # but measured ~15% worse on the memory-bound prefill
+        if cfg.family in ("dense", "moe", "vlm", "hybrid"):
+            with jax.named_scope("attention"):
+                x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
+                if cfg.family == "hybrid":
+                    mix, _, st2 = hymba_mixer(
+                        x, lp, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.hd, rope_theta=cfg.rope_theta,
+                        positions=positions, window=cfg.window,
+                        is_global=is_global, ssm_args=ssm_args,
+                        shard_ctx=(ctx.mesh, ctx.data_axes, "model"),
+                        mid_spec=ctx.mid_spec())
+                    ssm_out = {"state": st2.ssd, "conv": st2.conv}
+                    # caches for attention branch recomputed below
+                    q, k, v = attn_lib.gqa_project(
+                        x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
+                        cfg.n_heads, cfg.n_kv_heads, cfg.hd)
+                else:
+                    mix = attention_block(
+                        x, lp["attn"], n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.hd, rope_theta=cfg.rope_theta,
+                        positions=positions, causal=True, window=cfg.window,
+                        qk_norm=cfg.qk_norm, mrope_sections=cfg.mrope_sections,
+                        shard_ctx=(ctx.mesh, ctx.data_axes, "model"))
+                    q, k, v = attn_lib.gqa_project(
+                        x, lp["attn"]["wq"], lp["attn"]["wk"], lp["attn"]["wv"],
+                        cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+                        lp["attn"].get("q_norm") if cfg.qk_norm else None,
+                        lp["attn"].get("k_norm") if cfg.qk_norm else None)
+                if cfg.mrope_sections:
+                    k = apply_mrope(k, positions, cfg.mrope_sections,
+                                    cfg.rope_theta)
+                else:
+                    k = apply_rope(k, positions, cfg.rope_theta)
+                # last `cap` positions fill the ring cache; position p -> slot
+                # p % cap, so the packed window is rolled by s % cap.
+                if s >= cap:
+                    ks_ = jnp.roll(k[:, -cap:], s % cap, axis=1)
+                    vs_ = jnp.roll(v[:, -cap:], s % cap, axis=1)
+                else:
+                    pad = ((0, 0), (0, cap - s), (0, 0), (0, 0))
+                    ks_, vs_ = jnp.pad(k, pad), jnp.pad(v, pad)
+                kv_out = {"k": ks_, "v": vs_}
+                h = ctx.constrain(h + mix)
+            with _moe_scope(cfg, "moe.route"):
+                x = ctx.gather_seq(rms_norm(h, lp["ln2"]))
             if cfg.family == "moe":
                 y = moe_block(x, lp["moe"], mesh=ctx.mesh, placement=ctx.placement,
                               dcfg=ctx.dcfg, top_k=cfg.moe.top_k,
@@ -906,7 +912,8 @@ def prefill(params, inputs, positions, ctx: ModelContext, max_len: int,
                 w = jax.lax.with_sharding_constraint(
                     x @ lp["mlp"]["w_up"], ctx.mid_spec())
                 y = (jax.nn.silu(u) * w) @ lp["mlp"]["w_down"]
-            h = ctx.constrain(h + y)
+            with _moe_scope(cfg, "moe.combine"):
+                h = ctx.constrain(h + y)
         elif cfg.family == "ssm":
             x = ctx.gather_seq(rms_norm(h, lp["ln1"]))
             y, st2 = mamba2_mixer(x, lp["ssm"], mid_spec=ctx.mid_spec(),
@@ -923,8 +930,10 @@ def prefill(params, inputs, positions, ctx: ModelContext, max_len: int,
     xs = params["layers"] if traffic is None else (params["layers"], traffic)
     h, ys = _scan_layers(layer_fn, h, xs, cfg, ctx.remat)
     kv, ssm = ys[0], ys[1]
-    h = rms_norm(h, params["final_norm"].astype(cd))
-    logits = (h[:, -1] @ params["lm_head"].astype(cd)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h, params["final_norm"].astype(cd))
+        logits = (h[:, -1] @ params["lm_head"].astype(cd)).astype(
+            jnp.float32)
     has_kv = cfg.family in ("dense", "moe", "vlm", "hybrid")
     has_ssm = cfg.family in ("ssm", "hybrid")
     state = DecodeState(kv if has_kv else None, ssm if has_ssm else None,

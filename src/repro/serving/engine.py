@@ -29,12 +29,16 @@ timed prefill call, so the first request's TTFT is within noise of
 steady-state (regression-tested).
 
 Metrics: TTFT per request (p50/p95/p99 in ``stats()``), decode tok/s, queue
-latency, slot occupancy — plus, for MoE models with ``track_traffic=True``,
-per-admission expert-load statistics from the online traffic subsystem
-(``core/traffic.py``): the prefill threads an EMA ``TrafficState`` through
-the MoE islands (``moe`` per-layer, ``moe_ffn``/``moe_tx`` per stream
-block), and each admission's raw routing counts are reported as max/mean
-lane load and hot-expert share.  Under continuous admission this stream is
+latency, slot occupancy, and the continuous engine's host counters
+(``step_s``, ``wait_s``, ``prefill_s``) that match its profiler spans
+(``engine.step`` > ``engine.admit`` > ``engine.prefill`` /
+``engine.insert``, ``engine.decode``, ``engine.wait``, ``engine.retire``;
+recorded only while a profiler runs) — plus, for MoE models with
+``track_traffic=True``, per-admission expert-load statistics from the
+online traffic subsystem (``core/traffic.py``): the prefill threads an EMA
+``TrafficState`` through the MoE islands (``moe`` per-layer,
+``moe_ffn``/``moe_tx`` per stream block), and each admission's raw routing
+counts are reported as max/mean lane load and hot-expert share.  Under continuous admission this stream is
 *live*: stats update per admitted request rather than per wave, which is
 what lets a between-decodes re-layout policy (LAER-MoE style) act on them.
 
@@ -66,6 +70,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import commplan, relayout, traffic as traffic_lib
 from repro.models import lm
@@ -94,7 +99,9 @@ def greedy(out):
     """(logits, *rest) -> (greedy token ids, *rest), traced into the
     serving executables so sampling never runs (or compiles) eagerly."""
     logits, *rest = out
-    return (jnp.argmax(logits, -1).astype(jnp.int32), *rest)
+    with jax.named_scope("lm_head"):
+        ids = jnp.argmax(logits, -1).astype(jnp.int32)
+    return (ids, *rest)
 
 
 def _same_shardings(a, b) -> bool:
@@ -107,6 +114,7 @@ class Request:
     prompt: np.ndarray               # (S,) int32
     max_new: int
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None   # left the queue for a slot
     ttft_s: Optional[float] = None
     output: list = dataclasses.field(default_factory=list)
     done: bool = False
@@ -189,14 +197,18 @@ class _ServingBase:
     # ------------------------------------------- AOT-compiled executables ---
 
     def _prefill_callable(self) -> Callable:
-        """Prefill + greedy sampling in one executable: returns the first
-        token ids, never the (rows, vocab) logits."""
+        """Prefill + greedy sampling in one executable (``jit_serve_prefill``
+        in a profile): returns the first token ids, never the (rows, vocab)
+        logits."""
+        bundle, max_len = self.bundle, self.max_len
         if self.traffic is not None:
-            return lambda p, toks, tr, m: greedy(self.bundle.prefill(
-                p, {"tokens": toks}, self.max_len, traffic=tr,
-                traffic_mask=m))
-        return lambda p, toks: greedy(self.bundle.prefill(
-            p, {"tokens": toks}, self.max_len))
+            def serve_prefill(p, toks, tr, m):
+                return greedy(bundle.prefill(p, {"tokens": toks}, max_len,
+                                             traffic=tr, traffic_mask=m))
+        else:
+            def serve_prefill(p, toks):
+                return greedy(bundle.prefill(p, {"tokens": toks}, max_len))
+        return serve_prefill
 
     def _prefill_avals(self, rows: int, s: int):
         toks = jax.ShapeDtypeStruct((rows, s), jnp.int32)
@@ -228,16 +240,17 @@ class _ServingBase:
         exe = self._decode_exec.get(rows)
         if exe is None:
             t0 = time.perf_counter()
-            fn = lambda p, st, t: greedy(self.bundle.decode_step(
-                p, st, t, self.max_len))
+
+            def serve_decode(p, st, t):
+                return greedy(self.bundle.decode_step(p, st, t, self.max_len))
             st_avals = _avals_like(state)
             tok = jax.ShapeDtypeStruct((rows,), jnp.int32)
-            exe = jax.jit(fn).lower(params, st_avals, tok).compile()
+            exe = jax.jit(serve_decode).lower(params, st_avals, tok).compile()
             self.compile_count += 1
             out_tok, out_st = exe.output_shardings
             in_st = jax.tree.map(lambda x: x.sharding, st_avals)
             if not _same_shardings(out_st, in_st):
-                exe = (jax.jit(fn, out_shardings=(out_tok, in_st))
+                exe = (jax.jit(serve_decode, out_shardings=(out_tok, in_st))
                        .lower(params, st_avals, tok).compile())
                 self.compile_count += 1
             self._decode_exec[rows] = exe
@@ -451,7 +464,13 @@ class ContinuousServingEngine(_ServingBase):
         self.occupancy: list[float] = []     # per-step occupied fraction
         self.decode_steps = 0
         self.decode_tokens = 0
-        self.decode_s = 0.0
+        self.decode_s = 0.0                  # decode launch to tokens on host
+        # host clock of the engine.* profiler spans (no device sync added)
+        self.steps = 0
+        self.step_s = 0.0                    # whole step() calls
+        self.wait_s = 0.0                    # blocked on a device result
+        self.prefill_calls = 0
+        self.prefill_s = 0.0                 # per admit chunk, incl. its wait
         self._tok = np.full((max_batch,), pad_id, np.int32)
         self._state = None                   # pool DecodeState, built lazily
         self._insert_exec = None
@@ -466,11 +485,11 @@ class ContinuousServingEngine(_ServingBase):
                 ctx, per_slot=True)
 
     @staticmethod
-    def _insert_fn(pool: lm.DecodeState, new: lm.DecodeState,
-                   slots: jax.Array) -> lm.DecodeState:
+    def serve_insert(pool: lm.DecodeState, new: lm.DecodeState,
+                     slots: jax.Array) -> lm.DecodeState:
         """Scatter a freshly prefilled ``new`` state (rows = admit chunk)
         into the pool at ``slots``; out-of-bounds slot ids (pad lanes) are
-        dropped."""
+        dropped.  Compiled as ``jit_serve_insert``."""
         def upd(p, n):
             return p.at[:, slots].set(n.astype(p.dtype), mode="drop")
         kv = None if pool.kv is None else jax.tree.map(upd, pool.kv, new.kv)
@@ -499,14 +518,14 @@ class ContinuousServingEngine(_ServingBase):
             pool_avals = _avals_like(self._state)
             new_avals = _avals_like(new_state)
             slots = jax.ShapeDtypeStruct((self.admit_chunk,), jnp.int32)
-            exe = (jax.jit(self._insert_fn)
+            exe = (jax.jit(self.serve_insert)
                    .lower(pool_avals, new_avals, slots).compile())
             self.compile_count += 1
             out_sh = exe.output_shardings
             in_sh = jax.tree.map(lambda x: x.sharding, pool_avals)
             if not _same_shardings(out_sh, in_sh):
                 self._state = jax.device_put(self._state, out_sh)
-                exe = (jax.jit(self._insert_fn, out_shardings=out_sh)
+                exe = (jax.jit(self.serve_insert, out_shardings=out_sh)
                        .lower(_avals_like(self._state), new_avals, slots)
                        .compile())
                 self.compile_count += 1
@@ -560,6 +579,15 @@ class ContinuousServingEngine(_ServingBase):
         else:
             self._tok[i] = tok
 
+    def _fetch(self, x) -> np.ndarray:
+        """Block until a device result is on the host: the ``engine.wait``
+        span, its time added to ``wait_s``."""
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.wait"):
+            out = np.asarray(x)
+        self.wait_s += time.perf_counter() - t0
+        return out
+
     def _admit(self, params, retired: list) -> list[Request]:
         """Prefill-insert queued requests into free slots, one admit chunk
         at a time, while the rest of the pool's state sits untouched."""
@@ -568,24 +596,32 @@ class ContinuousServingEngine(_ServingBase):
             free = self.free_slots()
             take = min(self.admit_chunk, len(self.queue), len(free))
             reqs = [self.queue.popleft() for _ in range(take)]
+            now = time.perf_counter()
+            for r in reqs:
+                r.admitted_at = now
             s = max(self.bucket_of(len(r.prompt)) for r in reqs)
-            toks = np.full((self.admit_chunk, s), self.pad_id, np.int32)
-            valid = np.zeros((self.admit_chunk, s), bool)
-            for j, r in enumerate(reqs):
-                toks[j, s - len(r.prompt):] = r.prompt      # left-pad
-                valid[j, s - len(r.prompt):] = True
             exe = self.get_prefill(params, self.admit_chunk, s)  # pre-timed
             self._ensure_pool()
-            t_batch = jnp.asarray(toks)
-            if self.traffic is not None:
-                first, new_state, traffic = exe(
-                    params, t_batch, self.traffic, jnp.asarray(valid))
-                self.traffic = _uncommitted(traffic)
-                self._record_load()
-            else:
-                first, new_state = exe(params, t_batch)
-            first = np.asarray(first)
+            t0 = time.perf_counter()
+            with TraceAnnotation("engine.prefill", rids=[r.rid for r in reqs],
+                                 bucket=s, rows=len(reqs)):
+                toks = np.full((self.admit_chunk, s), self.pad_id, np.int32)
+                valid = np.zeros((self.admit_chunk, s), bool)
+                for j, r in enumerate(reqs):
+                    toks[j, s - len(r.prompt):] = r.prompt      # left-pad
+                    valid[j, s - len(r.prompt):] = True
+                t_batch = jnp.asarray(toks)
+                if self.traffic is not None:
+                    first, new_state, traffic = exe(
+                        params, t_batch, self.traffic, jnp.asarray(valid))
+                    self.traffic = _uncommitted(traffic)
+                    self._record_load()
+                else:
+                    first, new_state = exe(params, t_batch)
+                first = self._fetch(first)
             end = time.perf_counter()
+            self.prefill_s += end - t0
+            self.prefill_calls += 1
             # pad lanes point at slot id max_batch -> dropped by the scatter
             slot_arr = np.full((self.admit_chunk,), self.max_batch, np.int32)
             for j, r in enumerate(reqs):
@@ -593,8 +629,9 @@ class ContinuousServingEngine(_ServingBase):
                 slot_arr[j] = i
                 self.slots[i] = r
                 r.ttft_s = end - r.submitted_at
-            self._state = self._get_insert(new_state)(
-                self._state, new_state, jnp.asarray(slot_arr))
+            with TraceAnnotation("engine.insert"):
+                self._state = self._get_insert(new_state)(
+                    self._state, new_state, jnp.asarray(slot_arr))
             for j, r in enumerate(reqs):
                 # the prefill's argmax IS the request's first token (TTFT
                 # token); a max_new=1 request retires without ever decoding
@@ -605,8 +642,17 @@ class ContinuousServingEngine(_ServingBase):
     def step(self, params) -> list[Request]:
         """Admit into free slots, then decode the whole pool one token.
         Returns the requests retired this step."""
+        t_step = time.perf_counter()
+        with TraceAnnotation("engine.step"):
+            retired = self._step(params)
+        self.step_s += time.perf_counter() - t_step
+        self.steps += 1
+        return retired
+
+    def _step(self, params) -> list[Request]:
         retired: list[Request] = []
-        self._admit(params, retired)
+        with TraceAnnotation("engine.admit"):
+            self._admit(params, retired)
         occupied = [i for i, r in enumerate(self.slots) if r is not None]
         self.occupancy.append(len(occupied) / self.max_batch)
         if not occupied:
@@ -614,13 +660,16 @@ class ContinuousServingEngine(_ServingBase):
         self._ensure_pool()
         dec = self.get_decode(params, self._state, self.max_batch)
         t0 = time.perf_counter()
-        tok, self._state = dec(params, self._state, jnp.asarray(self._tok))
-        tok = np.asarray(tok)
+        with TraceAnnotation("engine.decode", rows=len(occupied)):
+            tok, self._state = dec(params, self._state,
+                                   jnp.asarray(self._tok))
+            tok = self._fetch(tok)
         self.decode_s += time.perf_counter() - t0
         self.decode_steps += 1
         self.decode_tokens += len(occupied)
-        for i in occupied:
-            self._retire_or_keep(i, int(tok[i]), retired)
+        with TraceAnnotation("engine.retire"):
+            for i in occupied:
+                self._retire_or_keep(i, int(tok[i]), retired)
         return retired
 
     def run(self, params) -> list[Request]:
@@ -637,4 +686,16 @@ class ContinuousServingEngine(_ServingBase):
             out["decode_steps"] = self.decode_steps
         if self.decode_s > 0:
             out["decode_tok_s"] = self.decode_tokens / self.decode_s
+        if self.steps:
+            out.update(steps=self.steps, step_s=self.step_s,
+                       wait_s=self.wait_s, prefill_calls=self.prefill_calls,
+                       prefill_s=self.prefill_s)
+        # a TTFT is the queue wait, then the admit chunk's prefill
+        waits = [r.admitted_at - r.submitted_at for r in self.finished
+                 if r.admitted_at is not None]
+        if waits:
+            out["p50_queue_wait_s"] = float(np.percentile(waits, 50))
+            out["p95_queue_wait_s"] = float(np.percentile(waits, 95))
+        if self.prefill_calls:
+            out["mean_prefill_s"] = self.prefill_s / self.prefill_calls
         return out

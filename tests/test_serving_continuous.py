@@ -182,3 +182,145 @@ def test_continuous_matches_batch1_oracle(family):
     assert len(eng.wave_loads) >= 2
     for w in eng.wave_loads:
         assert w["expert_tokens"].sum() > 0 and w["lane_imbalance"] >= 1.0
+
+
+def _op_names(exe) -> list[str]:
+    import re
+    return re.findall(r'op_name="([^"]*)"', exe.as_text())
+
+
+def test_executables_are_named_and_carry_the_model_scopes():
+    """The prefill, decode and insert executables are the modules
+    ``jit_serve_*`` of a profile, and their ops carry the model's named
+    scopes in their ``op_name`` metadata (what the trace reduction reads)."""
+    bundle, params, mesh, cfg = _bundle("moe")
+    eng = ContinuousServingEngine(bundle, max_batch=2, max_len=48,
+                                  buckets=(16,))
+    with mesh:
+        eng.warmup(params)
+    exes = {"prefill": eng._prefill_exec[(eng.admit_chunk, 16)],
+            "decode": eng._decode_exec[2], "insert": eng._insert_exec}
+    for name, exe in exes.items():
+        assert exe.as_text().startswith(f"HloModule jit_serve_{name},")
+    pre = "\n".join(_op_names(exes["prefill"]))
+    for scope in ("layers/while/body", "attention", "moe.route",
+                  "moe.dispatch", "moe.expert_ffn", "moe.combine", "lm_head"):
+        assert f"/{scope}/" in pre, scope
+    dec = _op_names(exes["decode"])
+    assert any("/layers/" in n and "/attention/" in n for n in dec)
+    for scope in ("moe.route", "moe.expert_ffn"):
+        assert any(f"/moe.decode/" in n and f"/{scope}/" in n
+                   for n in dec), scope
+    assert any("/lm_head/" in n for n in dec)
+    # prefill's shuffle scopes sit inside the layer scan
+    assert all("/layers/" in n for n in pre.splitlines()
+               if "/moe.dispatch/" in n)
+
+
+def test_profile_of_two_steps_holds_the_engine_spans(tmp_path):
+    """Two requests through a moe engine, two steps under the profiler: the
+    trace holds the engine's spans with their arguments."""
+    import glob
+    from jax.profiler import ProfileData
+    bundle, params, mesh, cfg = _bundle("moe")
+    eng = ContinuousServingEngine(bundle, max_batch=2, max_len=48,
+                                  buckets=(16,))
+    r = np.random.default_rng(5)
+    with mesh:
+        eng.warmup(params)
+        for _ in range(2):
+            eng.submit(r.integers(0, cfg.vocab, (16,)), max_new=4)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.step(params)
+            eng.step(params)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                events.setdefault(e.name, []).append(dict(e.stats))
+    assert len(events["engine.step"]) == 2
+    assert len(events["engine.decode"]) == 2
+    for name in ("engine.admit", "engine.insert", "engine.wait",
+                 "engine.retire"):
+        assert name in events, name
+    # one admit chunk (one row on this mesh) per request, in the first step
+    pre = events["engine.prefill"]
+    assert [p["rids"] for p in pre] == ["[0]", "[1]"]
+    assert all(p["rows"] == 1 and p["bucket"] == 16 for p in pre)
+    assert [d["rows"] for d in events["engine.decode"]] == [2, 2]
+    # one wait per prefill and one per decode
+    assert len(events["engine.wait"]) == 4
+
+
+def test_engine_counters_add_up():
+    """``wait_s`` is time inside steps, so it never exceeds ``step_s``; the
+    decode and prefill times hold their waits."""
+    bundle, params, mesh, cfg = _bundle("dense")
+    eng = ContinuousServingEngine(bundle, max_batch=2, max_len=48,
+                                  buckets=(16,))
+    r = np.random.default_rng(6)
+    with mesh:
+        eng.warmup(params)
+        assert eng.steps == 0 and eng.wait_s == 0.0
+        for _ in range(3):
+            eng.submit(r.integers(0, cfg.vocab, (16,)), max_new=3)
+        eng.run(params)
+    assert eng.steps >= 3 and eng.prefill_calls == 3   # one row a chunk
+    assert 0 < eng.wait_s <= eng.step_s
+    assert eng.decode_s + eng.prefill_s <= eng.step_s
+    assert eng.wait_s <= eng.decode_s + eng.prefill_s
+    for q in eng.finished:
+        assert q.submitted_at <= q.admitted_at
+        assert q.admitted_at - q.submitted_at <= q.ttft_s
+    st = eng.stats()
+    for k in ("steps", "step_s", "wait_s", "prefill_calls", "prefill_s"):
+        assert st[k] == getattr(eng, k), k
+    # the TTFT split an operator reads: queue wait, then the prefill
+    waits = sorted(q.admitted_at - q.submitted_at for q in eng.finished)
+    assert waits[0] <= st["p50_queue_wait_s"] <= st["p95_queue_wait_s"]
+    assert st["p95_queue_wait_s"] <= waits[-1]
+    assert st["mean_prefill_s"] == pytest.approx(eng.prefill_s / 3)
+
+
+def test_decode_expert_weight_views_belong_to_the_layer_scan():
+    """The decode's per-layer slice of the stacked expert weights, and the
+    block's view of this lane's experts, sit under the layer scan and no
+    finer scope: on a TPU the copy they can cost is ``layers.scan_io`` in
+    a trace (a fusion takes its root op's op_name), not the expert FFN."""
+    import re
+    import jax.numpy as jnp
+    from bench import scopes as S
+    bundle, params, mesh, cfg = _bundle("moe")
+    eng = ContinuousServingEngine(bundle, max_batch=2, max_len=48,
+                                  buckets=(16,))
+
+    def serve_decode(p, st, t):
+        return bundle.decode_step(p, st, t, eng.max_len)
+    with mesh:
+        eng._ensure_pool()
+        text = jax.jit(serve_decode).lower(
+            params, eng._state, jnp.zeros((2,), jnp.int32)).as_text(
+                dialect="hlo", debug_info=True)
+    e, d, f = cfg.moe.n_experts, cfg.d_model, cfg.moe.d_ff_expert
+    views = {f"bf16[{','.join(map(str, lead + w))}]"
+             for lead in ((), (1,), (1, 1)) for w in ((e, d, f), (e, f, d))}
+    # (result shape without its layout, opcode, op_name) of every op
+    ops = [(m.group(1), m.group(2), name)
+           for rest, name in re.findall(
+               r'^\s*(?:ROOT )?%?\S+ = (.*)op_name="([^"]*)"', text, re.M)
+           for m in [re.match(r"([a-z]\w*\[[\d,]*\])\S* ([a-z][\w-]*)\(",
+                              rest)] if m]
+    picked = [(op, name) for dims, op, name in ops if dims in views
+              and op in ("dynamic-slice", "reshape", "slice", "bitcast")]
+    # a layer's slice of w1, w3, w2, and the block's view of each
+    assert sum(op == "dynamic-slice" for op, _ in picked) >= 3
+    assert sum(op == "reshape" for op, _ in picked) >= 6
+    finer = set(S.SCOPES) - {"layers"}
+    for op, name in picked:
+        assert not finer & set(name.split("/")), (op, name)
+    assert all("/layers/" in name for op, name in picked
+               if op == "dynamic-slice")
